@@ -20,6 +20,10 @@ def main() -> None:
                     help="base seed, recorded in every BENCH_*.json")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         fig3_serverless_speedup,
         fig4_scaling,

@@ -18,7 +18,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import get_config
 from repro.core.compression import QSGDConfig
 from repro.core.convergence import ConvergenceDetector
@@ -82,7 +81,7 @@ def main():
 
     rules = activation_rules(cfg, ShapeConfig("ex", args.seq, args.batch, "train"), mesh)
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with axis_rules(rules):
             for i in range(args.steps):
                 b = loader.load(BatchKey(0, i // loader.num_batches, i % loader.num_batches))

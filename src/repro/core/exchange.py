@@ -409,8 +409,8 @@ class TopKExchange(ExchangeProtocol):
 
     ``ctx.topk_impl`` picks the select/scatter implementation:
     ``"jnp"`` is the ``lax.top_k`` + ``.at[].add`` oracle; ``"kernel"``
-    runs the Pallas bisection-threshold select+pack encoder and the fused
-    scatter-accumulate decoder (``repro.kernels.topk``). On exact
+    runs the Pallas bisection-threshold select+pack encoder
+    (``repro.kernels.topk``); both decode with XLA's scatter-add. On exact
     magnitude ties at the k-th position the two may pick different (equal
     magnitude) coordinates; otherwise they select identically.
     """
@@ -433,7 +433,7 @@ class TopKExchange(ExchangeProtocol):
 
     @staticmethod
     def _scatter(vbank, ibank, wrow, n: int, ctx):
-        """Fused sparse decode-reduce: (P, k) banks -> weighted dense (n,)."""
+        """Sparse decode-reduce: (P, k) banks -> weighted dense (n,)."""
         from repro.kernels import ops as kops
         from repro.kernels import ref as kref
 

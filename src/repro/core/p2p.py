@@ -37,7 +37,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import compression as C
 from repro.core import robust as R
 from repro.core.exchange import (
@@ -369,12 +368,6 @@ def lambda_shard(batch: Dict[str, jnp.ndarray], topo: Topology):
     if not (topo.serverless and topo.lambda_axis):
         return batch
     ax = topo.lambda_axis
-    auto = compat.auto_axes()
-    if auto is not None and ax not in auto:
-        # Old-JAX full-manual fallback: the lambda axis is manual here, so
-        # the GSPMD fan-out constraint would be rejected; peers replicate
-        # their compute over it instead (see repro.compat.shard_map).
-        return batch
     return jax.tree.map(
         lambda x: lax.with_sharding_constraint(x, P(*((ax,) + (None,) * (x.ndim - 1)))),
         batch,
@@ -531,7 +524,7 @@ def build_p2p_train_step(
             None if state.ef is None
             else jax.tree.map(lambda _: replicated, state.ef)
         )
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             peer_body,
             mesh=mesh,
             in_specs=(

@@ -86,7 +86,7 @@ def flash_attention(
     window: int = 0,
     block_q: int = 512,
     block_kv: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     B, Sq, H, D = q.shape
     _, Skv, K, _ = k.shape

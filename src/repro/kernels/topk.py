@@ -1,28 +1,29 @@
-"""Top-k select+pack / scatter-accumulate Pallas TPU kernels.
+"""Top-k select+pack Pallas TPU kernel.
 
 The sparsified exchange ships only the k largest-|x| entries of each
 gradient leaf as (value, int32 index) pairs. ``lax.top_k`` sorts the whole
-vector (O(n log n) and an awkward fit for the VPU); the kernel instead
-finds the k-th magnitude by **iterative norm thresholding** — a 64-step
-bisection on the threshold t, each step a full-tile compare+popcount
-(O(n) VPU work per step, no sort) — then packs the survivors into dense
-(k,) value/index banks with a cumsum prefix scan.
+vector (O(n log n) and an awkward fit for the VPU); the encoder instead
+finds the k-th magnitude by **bisection on its bit pattern**: the int32
+patterns of non-negative floats order like the floats, so 31 halvings of
+[0, +inf] pin the exact k-th largest |x|. Each halving is one tiled pass
+of compare+popcount over the leaf (O(n) VPU work, no sort); the bracket
+lives in SMEM across the grid, whose outer axis is the halving step and
+inner axis the (rows, 128) tile. The leaf never has to fit VMEM at once.
 
-Ties at the threshold are resolved in two tiers so the output is exactly
-k entries: everything strictly above the converged upper bracket is kept,
-and the remaining slots are filled with boundary-magnitude entries in
-ascending index order. For distinct magnitudes this matches ``lax.top_k``
-exactly; on exact magnitude ties only the tie-break order may differ
-(the decoded dense tensor is identical when tied values are equal).
+Packing the survivors into dense (k,) banks is a compaction (a prefix
+count and a gather), which Mosaic cannot express; it runs in XLA around
+the kernel. Ties at the threshold are resolved in two tiers so the output
+is exactly k entries: everything strictly above the threshold is kept,
+and the remaining slots go to threshold-magnitude entries in ascending
+index order. For distinct magnitudes this selects exactly ``lax.top_k``'s
+set; on exact magnitude ties only the tie-break order may differ (the
+decoded dense tensor is identical when tied values are equal). Entries
+come out in ascending index order.
 
-The decoder is a fused scatter-accumulate: all P peers' (k,) banks are
-dequantized and folded into the mixing-weighted dense sum in one VMEM
-pass — the sparse analogue of ``qsgd._dequant_reduce_kernel``.
-
-Both kernels operate on the whole (padded) leaf as a single VMEM block:
-per-leaf gradients at the repo's benchmark scale fit comfortably; leaves
-beyond the VMEM budget should use the ``jnp`` oracle path
-(``kernels/ref.py``), which the exchange layer keeps as the default.
+The decoder has no kernel: it is XLA's scatter-add
+(``ops.topk_scatter_accum``), since Mosaic has no scatter and XLA's
+scatter-add already folds the P*k weighted entries into the dense sum
+in one pass.
 """
 from __future__ import annotations
 
@@ -30,136 +31,73 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128  # lane width: flat vectors are tiled to (rows, LANE)
-ROW_TILE = 8  # sublane alignment for f32 tiles
-_BISECT_STEPS = 64  # enough to converge f32 brackets to adjacent floats
+MAX_TILE_ROWS = 256  # rows per tile: (256, 128) f32 = 128 KiB of VMEM
+_BISECT_STEPS = 31  # |x| bit patterns lie in [0, 0x7F800000]
+_HI0 = 0x7F800001  # one past +inf: no finite magnitude reaches it
 
 
-def _pad_rows(n: int) -> int:
+def _tiling(n: int):
+    """(rows per tile, total padded rows) for a flat length-n vector."""
     rows = -(-n // LANE)
-    return rows + ((-rows) % ROW_TILE)
+    rows += (-rows) % 8
+    tr = min(MAX_TILE_ROWS, rows)
+    return tr, rows + (-rows) % tr
 
 
-def _select_kernel(x_ref, out_v_ref, out_i_ref, *, n: int, k: int):
-    x = x_ref[...].astype(jnp.float32)  # (R, LANE)
-    rows, lanes = x.shape
-    flat_idx = (
-        jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0) * lanes
-        + jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
-    )
-    valid = flat_idx < n
-    mag = jnp.where(valid, jnp.abs(x), -1.0)  # padding can never be selected
+def _threshold_kernel(bits_ref, thr_ref, st_ref, *, k: int):
+    """Grid (step, tile): count |x| >= mid over all tiles, then halve."""
+    step, t = pl.program_id(0), pl.program_id(1)
 
-    # Bisection invariant: count(mag >= lo) >= k  and  count(mag >= hi) < k.
-    lo0 = jnp.float32(0.0)  # every valid |x| >= 0, and n >= k by contract
-    hi0 = jnp.max(mag) * jnp.float32(1.0 + 1e-6) + jnp.float32(1e-30)
+    @pl.when((step == 0) & (t == 0))
+    def _():
+        st_ref[0] = jnp.int32(0)  # lo: count(bits >= lo) >= k
+        st_ref[1] = jnp.int32(_HI0)  # hi: count(bits >= hi) < k
 
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = 0.5 * (lo + hi)
-        c = jnp.sum((mag >= mid).astype(jnp.int32))
-        big = c >= k
-        return jnp.where(big, mid, lo), jnp.where(big, hi, mid)
+    @pl.when(t == 0)
+    def _():
+        st_ref[2] = jnp.int32(0)
 
-    lo, hi = jax.lax.fori_loop(0, _BISECT_STEPS, body, (lo0, hi0))
+    lo, hi = st_ref[0], st_ref[1]
+    mid = lo + ((hi - lo) >> 1)
+    st_ref[2] += jnp.sum((bits_ref[...] >= mid).astype(jnp.int32))
 
-    # Two-tier exact-k selection: keep everything strictly above the upper
-    # bracket (count < k), then fill the remaining slots with boundary
-    # entries (lo <= mag < hi) in ascending index order.
-    sure = (mag >= hi).reshape(-1)
-    edge = ((mag >= lo) & (mag < hi)).reshape(-1)
-    n_sure = jnp.sum(sure.astype(jnp.int32))
-    fill = k - n_sure
-    sure_rank = jnp.cumsum(sure.astype(jnp.int32)) - 1
-    edge_rank = jnp.cumsum(edge.astype(jnp.int32)) - 1
-    take_edge = edge & (edge_rank < fill)
-    take = sure | take_edge
-    slot = jnp.where(sure, sure_rank, n_sure + edge_rank)
-
-    kp = out_v_ref.shape[0]
-    flat_v = x.reshape(-1)
-    flat_i = flat_idx.reshape(-1)
-    tgt = jnp.where(take, slot, kp)  # non-selected entries dropped
-    out_v_ref[...] = (
-        jnp.zeros((kp,), jnp.float32)
-        .at[tgt]
-        .set(jnp.where(take, flat_v, 0.0), mode="drop")
-    )
-    out_i_ref[...] = (
-        jnp.zeros((kp,), jnp.int32)
-        .at[tgt]
-        .set(jnp.where(take, flat_i, 0), mode="drop")
-    )
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _():
+        big = st_ref[2] >= k
+        new_lo = jnp.where(big, mid, lo)
+        st_ref[0] = new_lo
+        st_ref[1] = jnp.where(big, hi, mid)
+        thr_ref[0, 0] = new_lo
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def topk_select_pack(x: jnp.ndarray, k: int, *, interpret: bool = True):
+def topk_select_pack(x: jnp.ndarray, k: int, *, interpret: bool):
     """x: (n,) f32 -> (values f32 (k,), indices int32 (k,)) of the k largest |x|."""
     n = x.shape[0]
     assert 1 <= k <= n, f"k={k} out of range for n={n}"
-    rows = _pad_rows(n)
-    xp = jnp.pad(x.astype(jnp.float32), (0, rows * LANE - n)).reshape(rows, LANE)
-    kp = k + ((-k) % LANE)
-    vals, idx = pl.pallas_call(
-        functools.partial(_select_kernel, n=n, k=k),
-        grid=(1,),
-        in_specs=[pl.BlockSpec((rows, LANE), lambda i: (0, 0))],
-        out_specs=[
-            pl.BlockSpec((kp,), lambda i: (0,)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((kp,), jnp.float32),
-            jax.ShapeDtypeStruct((kp,), jnp.int32),
-        ],
+    tr, rows = _tiling(n)
+    flat = x.astype(jnp.float32)
+    bits = lax.bitcast_convert_type(jnp.abs(flat), jnp.int32)
+    padded = jnp.pad(bits, (0, rows * LANE - n), constant_values=-1)  # never selected
+    thr = pl.pallas_call(
+        functools.partial(_threshold_kernel, k=k),
+        grid=(_BISECT_STEPS, rows // tr),
+        in_specs=[pl.BlockSpec((tr, LANE), lambda s, t: (t, 0))],
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((3,), jnp.int32)],
         interpret=interpret,
-    )(xp)
-    return vals[:k], idx[:k]
-
-
-def _scatter_kernel(v_ref, i_ref, w_ref, out_ref):
-    v = v_ref[...].astype(jnp.float32)  # (P, kp)
-    w = w_ref[...].astype(jnp.float32)  # (P,)
-    contrib = (v * w[:, None]).reshape(-1)
-    tgt = i_ref[...].reshape(-1)
-    out_ref[...] = (
-        jnp.zeros(out_ref.shape, jnp.float32).at[tgt].add(contrib, mode="drop")
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("n", "interpret"))
-def topk_scatter_accum(
-    vals: jnp.ndarray,
-    idx: jnp.ndarray,
-    w: jnp.ndarray,
-    n: int,
-    *,
-    interpret: bool = True,
-):
-    """Fused sparse decode-reduce.
-
-    vals (P, k) f32, idx (P, k) int32, w (P,) f32 -> dense (n,) f32 holding
-    sum_p w[p] * scatter(vals[p], idx[p]) in one pass. Padding slots carry
-    value 0.0 so their scatter-adds are no-ops.
-    """
-    P, k = vals.shape
-    kp = k + ((-k) % LANE)
-    if kp != k:
-        vals = jnp.pad(vals.astype(jnp.float32), ((0, 0), (0, kp - k)))
-        idx = jnp.pad(idx, ((0, 0), (0, kp - k)))
-    np_ = n + ((-n) % LANE)
-    out = pl.pallas_call(
-        _scatter_kernel,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((P, kp), lambda i: (0, 0)),
-            pl.BlockSpec((P, kp), lambda i: (0, 0)),
-            pl.BlockSpec((P,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((np_,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.float32),
-        interpret=interpret,
-    )(vals.astype(jnp.float32), idx, w.astype(jnp.float32))
-    return out[:n]
+    )(padded.reshape(rows, LANE))[0, 0]
+    # compaction (XLA): all entries above the threshold, then the first
+    # threshold-magnitude entries in index order up to exactly k
+    sure = bits > thr
+    edge = bits == thr
+    fill = k - jnp.sum(sure.astype(jnp.int32))
+    take = sure | (edge & (jnp.cumsum(edge.astype(jnp.int32)) <= fill))
+    idx = jnp.nonzero(take, size=k)[0].astype(jnp.int32)
+    return flat[idx], idx

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat, models
+from repro import models
 from repro.configs import ASSIGNED_ARCHS, SHAPES, get_config
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.p2p import TrainState, Topology
@@ -116,7 +116,7 @@ def lower_one(
     )
     rules = SH.activation_rules(cfg, shape, mesh, peer_axes=topo.peer_axes)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with axis_rules(rules):
             if shape.mode == "train":
                 opt = adam() if optimizer == "adam" else sgd(momentum=0.9)

@@ -14,14 +14,13 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh(data: Optional[int] = None, model: int = 1):
@@ -29,8 +28,8 @@ def make_host_mesh(data: Optional[int] = None, model: int = 1):
     n = len(jax.devices())
     if data is None:
         data = n // model
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 # Hardware constants for the roofline analysis (TPU v5e).

@@ -12,8 +12,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat, models
+from repro import models
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.sharding import activation_rules
 from repro.models.layers import axis_rules
@@ -32,6 +33,7 @@ def main(argv=None):
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -68,7 +70,7 @@ def main(argv=None):
     def do_prefill(params, state, prompt):
         return models.prefill(params, state, {"tokens": prompt}, cfg)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with axis_rules(rules):
             t0 = time.time()
             logits, state = do_prefill(params, state, prompts)  # one-shot prefill

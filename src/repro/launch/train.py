@@ -3,10 +3,14 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-3b --reduced \
         --steps 50 --batch 8 --seq 64 --exchange allgather_mean
 
-On this CPU container you train REDUCED variants (or the paper's CNNs via
-benchmarks/); on a TPU slice the same driver runs the full configs with the
-production mesh. Any protocol registered in
-``repro.core.exchange`` is accepted by ``--exchange``.
+    PYTHONPATH=src python -m repro.launch.train --arch vgg11 --full \
+        --steps 4 --batch 64 --exchange allgather_mean
+
+On a CPU host you train REDUCED variants; on a TPU the same CLI runs the
+full configs (``--full``). The paper's CNNs (vgg11, squeezenet1.1,
+mobilenet-v3-small) train on procedural images, the language models on
+procedural tokens. Any protocol registered in ``repro.core.exchange`` is
+accepted by ``--exchange``.
 """
 from __future__ import annotations
 
@@ -17,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat, models
+from repro import models
 from repro.configs import get_config, reduced
 from repro.core.compression import QSGDConfig
 from repro.core.convergence import ConvergenceDetector
@@ -28,20 +32,34 @@ from repro.core.exchange import available_exchanges, get_exchange
 from repro.core.p2p import Topology
 from repro.core.robust import ATTACK_KINDS, AdversarySpec
 from repro.data import BatchKey, DataLoader, Partitioner, make_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.sharding import activation_rules
 from repro.models.layers import axis_rules
 from repro.optim import adam, sgd
 from repro.optim.schedules import warmup_cosine
 from repro.train import P2PTrainer
-from repro.configs.base import ShapeConfig
+from repro.configs.base import ModelConfig, ShapeConfig
 
 
-def make_lm_batch(loader: DataLoader, key: BatchKey, vocab: int):
+def make_train_dataset(cfg: ModelConfig, seq: int):
+    """Procedural data for the config: images for a CNN, tokens otherwise."""
+    if cfg.family == "cnn":
+        return make_dataset(
+            "mnist" if cfg.image_channels == 1 else "cifar",
+            image_hw=cfg.image_size, channels=cfg.image_channels,
+            num_classes=cfg.num_classes,
+        )
+    return make_dataset("lm", size=200_000, vocab_size=cfg.vocab_size, seq_len=seq)
+
+
+def make_batch(loader: DataLoader, key: BatchKey, cfg: ModelConfig):
     b = loader.load(key)
+    if cfg.family == "cnn":
+        return {"images": jnp.asarray(b["images"]), "labels": jnp.asarray(b["labels"])}
     return {
-        "tokens": jnp.asarray(b["tokens"] % vocab),
-        "labels": jnp.asarray(b["labels"] % vocab),
+        "tokens": jnp.asarray(b["tokens"] % cfg.vocab_size),
+        "labels": jnp.asarray(b["labels"] % cfg.vocab_size),
     }
 
 
@@ -69,8 +87,8 @@ def main(argv=None):
     ap.add_argument("--topk-frac", type=float, default=0.01,
                     help="topk: fraction of gradient entries shipped")
     ap.add_argument("--topk-impl", default="jnp", choices=["jnp", "kernel"],
-                    help="topk select/scatter implementation: jnp oracle or "
-                         "the Pallas select+pack / scatter-accumulate kernels")
+                    help="topk select implementation: jnp oracle or the "
+                         "Pallas select+pack kernel")
     ap.add_argument("--qsgd-impl", default="jnp", choices=["jnp", "kernel"],
                     help="qsgd codec implementation: jnp oracle or the Pallas "
                          "quantize + fused decode-dequantize-reduce kernels")
@@ -149,6 +167,7 @@ def main(argv=None):
     ap.add_argument("--budget-usd", type=float, default=None,
                     help="scheduler: whole-cluster epoch budget in dollars")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     import dataclasses as _dc
 
@@ -234,7 +253,7 @@ def main(argv=None):
         if plan is not None:
             print(f"shard plan: {plan.describe()}")
 
-    ds = make_dataset("lm", size=200_000, vocab_size=cfg.vocab_size, seq_len=args.seq)
+    ds = make_train_dataset(cfg, args.seq)
     loader = DataLoader(Partitioner(ds, 1), 0, args.batch)
 
     shape = ShapeConfig("host", args.seq, args.batch, "train")
@@ -243,12 +262,12 @@ def main(argv=None):
 
     t0 = time.time()
     step_times = []
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with axis_rules(rules):
             for i in range(args.steps):
-                batch = make_lm_batch(
+                batch = make_batch(
                     loader, BatchKey(0, i // loader.num_batches, i % loader.num_batches),
-                    cfg.vocab_size,
+                    cfg,
                 )
                 ts = time.time()
                 state, metrics = trainer.step(state, batch)

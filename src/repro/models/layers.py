@@ -16,7 +16,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 Params = Dict[str, Any]
 
@@ -39,15 +39,12 @@ def axis_rules(rules: Dict[str, Any]):
         _AXIS_RULES = old
 
 
-def _auto_axes() -> Optional[frozenset]:
-    """Mesh axes currently in Auto (GSPMD) mode; None if no mesh context."""
-    from repro.compat import auto_axes
-
-    return auto_axes()
-
-
 def logical_to_spec(*names: Optional[str]) -> P:
-    auto = _auto_axes()
+    am = jax.sharding.get_abstract_mesh()
+    # mesh axes GSPMD owns; with no mesh context every rule applies
+    auto = None if am.empty else frozenset(
+        n for n, t in zip(am.axis_names, am.axis_types) if t == AxisType.Auto
+    )
 
     def resolve(n):
         if not n:

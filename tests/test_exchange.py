@@ -201,12 +201,10 @@ def test_sync_protocols_match_reference_mean_multidevice():
         """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.core.compression import QSGDConfig
         from repro.core.exchange import ExchangeContext, get_exchange
 
-        mesh = compat.make_mesh((4,), ("data",),
-                                axis_types=(compat.AxisType.Auto,))
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         g_global = {
             "w": jax.random.normal(jax.random.PRNGKey(0), (4, 6, 33)),
             "b": jax.random.normal(jax.random.PRNGKey(1), (4, 17)),
@@ -223,13 +221,13 @@ def test_sync_protocols_match_reference_mean_multidevice():
                 avg, _ = proto.combine(per_peer, ctx, key=key)
                 return avg
 
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P("data"), g_global),),
                 out_specs=jax.tree.map(lambda _: P(), g_global),
                 axis_names={"data"}, check_vma=False,
             )
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 return jax.jit(fn)(g_global)
 
         for name, kw, tol in [

@@ -323,11 +323,9 @@ def test_device_full_graph_bit_exact_and_ring_mixes():
         """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.core.p2p import Topology, exchange_context
 
-        mesh = compat.make_mesh((4,), ("data",),
-                                axis_types=(compat.AxisType.Auto,))
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         g_global = {
             "w": jax.random.normal(jax.random.PRNGKey(0), (4, 6, 33)),
             "b": jax.random.normal(jax.random.PRNGKey(1), (4, 17)),
@@ -344,13 +342,13 @@ def test_device_full_graph_bit_exact_and_ring_mixes():
                 avg, _ = proto.combine(per, ctx, key=None)
                 return jax.tree.map(lambda x: x[None], avg)
 
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P("data"), g_global),),
                 out_specs=jax.tree.map(lambda _: P("data"), g_global),
                 axis_names={"data"}, check_vma=False,
             )
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 return jax.jit(fn)(g_global), ctx
 
         legacy, _ = run()

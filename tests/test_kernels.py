@@ -27,11 +27,11 @@ def test_qsgd_quantize_matches_ref(nb, bucket, s):
     key = jax.random.PRNGKey(nb * 1000 + bucket + s)
     x = jax.random.normal(key, (nb, bucket)) * 3.0
     u = jax.random.uniform(jax.random.fold_in(key, 1), (nb, bucket))
-    lev_k, nrm_k = qsgd_quantize(x, u, s)
+    lev_k, nrm_k = qsgd_quantize(x, u, s, interpret=True)
     lev_r, nrm_r = qsgd_quantize_ref(x, u, s)
     np.testing.assert_array_equal(np.asarray(lev_k), np.asarray(lev_r))
     np.testing.assert_allclose(np.asarray(nrm_k), np.asarray(nrm_r), rtol=1e-6)
-    dq_k = qsgd_dequantize(lev_k, nrm_k, s)
+    dq_k = qsgd_dequantize(lev_k, nrm_k, s, interpret=True)
     dq_r = qsgd_dequantize_ref(lev_r, nrm_r, s)
     np.testing.assert_allclose(np.asarray(dq_k), np.asarray(dq_r), rtol=1e-6)
 
@@ -39,9 +39,9 @@ def test_qsgd_quantize_matches_ref(nb, bucket, s):
 def test_qsgd_zero_bucket():
     x = jnp.zeros((4, 128))
     u = jnp.full((4, 128), 0.5)
-    lev, nrm = qsgd_quantize(x, u, 15)
+    lev, nrm = qsgd_quantize(x, u, 15, interpret=True)
     assert np.all(np.asarray(lev) == 0)
-    dq = qsgd_dequantize(lev, nrm, 15)
+    dq = qsgd_dequantize(lev, nrm, 15, interpret=True)
     assert np.all(np.asarray(dq) == 0)
 
 
@@ -66,7 +66,7 @@ def test_ssd_kernel_matches_ref(B, S, H, P, G, N, chunk):
     Bm = jax.random.normal(jax.random.fold_in(key, 3), (B, S, G, N)) * 0.3
     Cm = jax.random.normal(jax.random.fold_in(key, 4), (B, S, G, N)) * 0.3
     y_ref, _ = ssd_scan_ref(x, dt, A, Bm, Cm)
-    y_k = ssd_scan_pallas(x, dt, A, Bm, Cm, chunk=chunk)
+    y_k = ssd_scan_pallas(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_ref), atol=2e-5, rtol=2e-4)
 
 
@@ -79,7 +79,7 @@ def test_ssd_kernel_bf16_inputs():
     Bm = (jax.random.normal(key, (B, S, G, N)) * 0.3).astype(jnp.bfloat16)
     Cm = (jax.random.normal(key, (B, S, G, N)) * 0.3).astype(jnp.bfloat16)
     y_ref, _ = ssd_scan_ref(x, dt, A, Bm, Cm)
-    y_k = ssd_scan_pallas(x, dt, A, Bm, Cm, chunk=32)
+    y_k = ssd_scan_pallas(x, dt, A, Bm, Cm, chunk=32, interpret=True)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_ref), atol=3e-2, rtol=3e-2)
 
 
@@ -103,7 +103,8 @@ def test_flash_attention_matches_ref(B, S, H, K, D, softcap, window, bq, bkv):
     k = jax.random.normal(jax.random.fold_in(key, 1), (B, S, K, D)) * 0.5
     v = jax.random.normal(jax.random.fold_in(key, 2), (B, S, K, D)) * 0.5
     o_k = flash_attention(
-        q, k, v, causal=True, softcap=softcap, window=window, block_q=bq, block_kv=bkv
+        q, k, v, causal=True, softcap=softcap, window=window, block_q=bq, block_kv=bkv,
+        interpret=True,
     )
     o_r = attention_ref(q, k, v, causal=True, softcap=softcap, window=window)
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r), atol=2e-5, rtol=2e-4)
@@ -116,7 +117,7 @@ def test_flash_attention_dtypes(dtype):
     q = (jax.random.normal(key, (B, S, H, D)) * 0.5).astype(dtype)
     k = (jax.random.normal(jax.random.fold_in(key, 1), (B, S, K, D)) * 0.5).astype(dtype)
     v = (jax.random.normal(jax.random.fold_in(key, 2), (B, S, K, D)) * 0.5).astype(dtype)
-    o_k = flash_attention(q, k, v, block_q=32, block_kv=32)
+    o_k = flash_attention(q, k, v, block_q=32, block_kv=32, interpret=True)
     assert o_k.dtype == dtype
     o_r = attention_ref(q, k, v)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4

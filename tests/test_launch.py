@@ -103,3 +103,54 @@ def test_batch_specs_sanitized_for_odd_batches():
 
     rules = SH.activation_rules(cfg, shape, M())
     assert rules["batch"] is None
+
+
+def test_train_main_runs_a_cnn_on_images(monkeypatch, tmp_path, capsys):
+    """--arch <cnn> trains on procedural images through the CLI."""
+    import math
+
+    import numpy as np
+
+    from repro.launch.train import main
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    state = main(["--arch", "squeezenet1.1", "--steps", "2", "--batch", "4",
+                  "--data-parallel", "1", "--log-every", "1"])
+    assert int(state.step) == 2
+    losses = [float(line.split()[3]) for line in capsys.readouterr().out.splitlines()
+              if line.startswith("step ")]
+    assert len(losses) == 2 and all(math.isfinite(x) for x in losses)
+    assert all(np.isfinite(np.asarray(x)).all() for x in jax.tree.leaves(state.params))
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_lands_in_its_directory(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins; unset, the cache is <repo>/.jax_cache."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    from repro.launch.compile_cache import REPO_CACHE_DIR
+
+    repo = Path(__file__).resolve().parents[1]
+    assert REPO_CACHE_DIR == repo / ".jax_cache"
+    want = tmp_path / "cache" if env_dir else REPO_CACHE_DIR
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(repo / "src"))
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    # a function no other test compiles, so its cache entry is new here
+    tag = f"cache_probe_{os.getpid()}_{int(env_dir)}"
+    script = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        f"def {tag}(x):\n    return x * 3 + 1\n"
+        f"jax.jit({tag})(jnp.ones(5))\n"
+    )
+    out = subprocess.run([sys.executable, "-c", script], env=env, check=True,
+                         capture_output=True, text=True).stdout
+    assert out.strip() == str(want)
+    assert any(p.name.startswith(f"jit_{tag}-") for p in want.iterdir())

@@ -26,7 +26,8 @@ def test_exchange_modes_equivalent_multidevice():
     script = textwrap.dedent(
         """
         import jax, jax.numpy as jnp
-        from repro.compat import AxisType, make_mesh, set_mesh
+        from jax import make_mesh, set_mesh
+        from jax.sharding import AxisType
         from repro.configs import get_config, reduced
         from repro.core.p2p import Topology
         from repro.core.compression import QSGDConfig

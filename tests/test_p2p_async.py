@@ -15,7 +15,8 @@ def test_async_mailbox_exchange_multidevice():
     script = textwrap.dedent(
         """
         import jax, jax.numpy as jnp
-        from repro.compat import AxisType, make_mesh, set_mesh
+        from jax import make_mesh, set_mesh
+        from jax.sharding import AxisType
         from repro.configs import get_config, reduced
         from repro.core.p2p import Topology, init_mailbox
         from repro.train import build_train_step, init_train_state

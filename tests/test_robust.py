@@ -279,9 +279,8 @@ def test_cluster_refuses_adversary_on_sharded_protocol():
 def test_device_path_refuses_stale_replay():
     from repro.core.p2p import Topology, build_p2p_train_step
     from repro.optim import sgd as _sgd
-    from repro import compat
 
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="host mailbox"):
         build_p2p_train_step(
             lambda p, b: (jnp.float32(0), jnp.float32(0)),

@@ -371,11 +371,9 @@ def test_reduce_scatter_matches_mean_multidevice():
         """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.core.exchange import ExchangeContext, get_exchange
 
-        mesh = compat.make_mesh((4,), ("data",),
-                                axis_types=(compat.AxisType.Auto,))
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         # 6*33 + 17 = 215 elements: not divisible by 4 -> padding exercised
         g_global = {
             "w": jax.random.normal(jax.random.PRNGKey(0), (4, 6, 33)),
@@ -390,13 +388,13 @@ def test_reduce_scatter_matches_mean_multidevice():
             avg, _ = proto.combine(per_peer, ctx)
             return avg
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P("data"), g_global),),
             out_specs=jax.tree.map(lambda _: P(), g_global),
             axis_names={"data"}, check_vma=False,
         )
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             avg = jax.jit(fn)(g_global)
         err = max(
             float(jnp.abs(a - b).max())
