@@ -124,6 +124,7 @@ class ExchangeProtocol(abc.ABC):
     sharded: ClassVar[bool] = False  # True: shards, not pytrees, on the wire
     lossy: ClassVar[bool] = False  # True: codec drops information (EF applies)
     hierarchical: ClassVar[bool] = False  # True: multi-level tree reduce
+    scoped: ClassVar[bool] = False  # True: opens p2p.encode/gather/decode itself
 
     # -- device path --------------------------------------------------------
     def init_state(self, grads_like, ctx: ExchangeContext):
@@ -334,6 +335,7 @@ class QSGDExchange(ExchangeProtocol):
 
     requires_key = True
     lossy = True
+    scoped = True
 
     def _cfg(self, ctx) -> C.QSGDConfig:
         return ctx.qsgd or C.QSGDConfig()
@@ -342,30 +344,36 @@ class QSGDExchange(ExchangeProtocol):
         """Shared device path. The decode side is the FUSED formulation
         ``dequant_reduce`` (one pass over all P gathered int8 banks,
         mixing-weighted) — Pallas kernel when ``cfg.impl == "kernel"``,
-        jnp reference otherwise. Returns (avg, local_image-or-None).
+        jnp reference otherwise. Returns (avg, local_image-or-None). Each
+        stage runs in its layer's scope: ``p2p.encode``, ``p2p.gather``,
+        ``p2p.decode``.
         """
         qcfg = self._cfg(ctx)
         if key is None:
             raise ValueError("qsgd exchange requires an rng key")
-        key = jax.random.fold_in(key, lax.axis_index(ctx.axis))
-
-        w = None if ctx.mixing is None else ctx.mix_row()[0]
+        leaves, treedef = jax.tree_util.tree_flatten(grads)
+        with jax.named_scope("p2p.encode"):
+            key = jax.random.fold_in(key, lax.axis_index(ctx.axis))
+            keys = jax.random.split(key, len(leaves))
+        with jax.named_scope("p2p.decode"):
+            w = None if ctx.mixing is None else ctx.mix_row()[0]
 
         def leaf(g, k):
-            payload = C.quantize(g, k, qcfg)  # routes cfg.impl for encode
-            lev = lax.all_gather(payload["levels"], ctx.axis)  # (P, nb, B)
-            nrm = lax.all_gather(payload["norms"], ctx.axis)  # (P, nb)
-            P_ = lev.shape[0]
-            wrow = jnp.full((P_,), 1.0 / P_, jnp.float32) if w is None else w
-            flat = C.dequant_reduce(lev, nrm, wrow, qcfg).reshape(-1)
-            avg = flat[: g.size].reshape(g.shape)
-            if not want_local:
-                return avg, None
-            local = C.dequantize(payload, qcfg).reshape(g.shape)
+            with jax.named_scope("p2p.encode"):
+                payload = C.quantize(g, k, qcfg)  # routes cfg.impl for encode
+            with jax.named_scope("p2p.gather"):
+                lev = lax.all_gather(payload["levels"], ctx.axis)  # (P, nb, B)
+                nrm = lax.all_gather(payload["norms"], ctx.axis)  # (P, nb)
+            with jax.named_scope("p2p.decode"):
+                P_ = lev.shape[0]
+                wrow = jnp.full((P_,), 1.0 / P_, jnp.float32) if w is None else w
+                flat = C.dequant_reduce(lev, nrm, wrow, qcfg).reshape(-1)
+                avg = flat[: g.size].reshape(g.shape)
+                if not want_local:
+                    return avg, None
+                local = C.dequantize(payload, qcfg).reshape(g.shape)
             return avg, local
 
-        leaves, treedef = jax.tree_util.tree_flatten(grads)
-        keys = jax.random.split(key, len(leaves))
         pairs = [leaf(g, k) for g, k in zip(leaves, keys)]
         avg = jax.tree_util.tree_unflatten(treedef, [p[0] for p in pairs])
         if not want_local:
@@ -416,6 +424,7 @@ class TopKExchange(ExchangeProtocol):
     """
 
     lossy = True
+    scoped = True
 
     @staticmethod
     def _k(n: int, frac: float) -> int:
@@ -443,29 +452,33 @@ class TopKExchange(ExchangeProtocol):
 
     def _combine(self, grads, ctx, *, want_local: bool):
         frac = ctx.topk_frac
-        w = None if ctx.mixing is None else ctx.mix_row()[0]
+        with jax.named_scope("p2p.decode"):
+            w = None if ctx.mixing is None else ctx.mix_row()[0]
 
         def leaf(g):
-            flat = g.astype(jnp.float32).reshape(-1)
-            k = self._k(flat.size, frac)
-            vals, idx = self._select(flat, k, ctx)
-            vbank = lax.all_gather(vals.astype(ctx.wire_dtype), ctx.axis)  # (P, k)
-            ibank = lax.all_gather(idx, ctx.axis)  # (P, k)
-            P_ = vbank.shape[0]
-            wrow = jnp.full((P_,), 1.0 / P_, jnp.float32) if w is None else w
-            dense = self._scatter(
-                vbank.astype(jnp.float32), ibank, wrow, flat.size, ctx
-            )
-            avg = dense.reshape(g.shape)
-            if not want_local:
-                return avg, None
-            local = self._scatter(
-                vals[None].astype(jnp.float32),
-                idx[None],
-                jnp.ones((1,), jnp.float32),
-                flat.size,
-                ctx,
-            ).reshape(g.shape)
+            with jax.named_scope("p2p.encode"):
+                flat = g.astype(jnp.float32).reshape(-1)
+                k = self._k(flat.size, frac)
+                vals, idx = self._select(flat, k, ctx)
+            with jax.named_scope("p2p.gather"):
+                vbank = lax.all_gather(vals.astype(ctx.wire_dtype), ctx.axis)  # (P, k)
+                ibank = lax.all_gather(idx, ctx.axis)  # (P, k)
+            with jax.named_scope("p2p.decode"):
+                P_ = vbank.shape[0]
+                wrow = jnp.full((P_,), 1.0 / P_, jnp.float32) if w is None else w
+                dense = self._scatter(
+                    vbank.astype(jnp.float32), ibank, wrow, flat.size, ctx
+                )
+                avg = dense.reshape(g.shape)
+                if not want_local:
+                    return avg, None
+                local = self._scatter(
+                    vals[None].astype(jnp.float32),
+                    idx[None],
+                    jnp.ones((1,), jnp.float32),
+                    flat.size,
+                    ctx,
+                ).reshape(g.shape)
             return avg, local
 
         leaves, treedef = jax.tree_util.tree_flatten(grads)

@@ -26,6 +26,7 @@ access kept for backward compatibility).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import warnings
 from dataclasses import dataclass
@@ -414,7 +415,15 @@ def build_p2p_train_step(
             )
         attack_mask = jnp.asarray(adversary.mask(ctx.num_peers))
 
-    def peer_body(params, opt_state, step_idx, key, batch, mailbox, ef):
+    def exchange_scope():
+        """``p2p.exchange`` around a protocol that opens no scopes of its own."""
+        if protocol.scoped:
+            return contextlib.nullcontext()
+        return jax.named_scope("p2p.exchange")
+
+    def fan_out(params, step_idx, key, batch):
+        """The peer's loss and gradient over its batch (micro-batches
+        accumulated in fp32), clipped and, on attacker ranks, poisoned."""
         batch = lambda_shard(batch, topo)
         if topo.cast_params_once:
             # One bf16 cast per step: ZeRO weight gathers then move bf16
@@ -473,6 +482,16 @@ def build_p2p_train_step(
             grads = jax.tree.map(
                 lambda h, p: jnp.where(attack_mask[r], p, h), grads, poisoned
             )
+        return grads, loss, aux, gnorm, step_key
+
+    def peer_body(params, opt_state, step_idx, key, batch, mailbox, ef):
+        # One named scope per layer of the round (p2p.fanout, p2p.ef,
+        # p2p.exchange and the codec's p2p.encode / p2p.gather / p2p.decode,
+        # p2p.optimizer). They never nest, and each lands in the op_name of
+        # every HLO instruction it holds, so a device trace can be split by
+        # layer; they cost nothing at run time.
+        with jax.named_scope("p2p.fanout"):
+            grads, loss, aux, gnorm, step_key = fan_out(params, step_idx, key, batch)
         if protocol is None:
             avg, new_mailbox, new_ef = grads, mailbox, ef
         elif ef is not None:
@@ -480,31 +499,37 @@ def build_p2p_train_step(
             # before encoding, then keep what the codec dropped. local_image
             # is the decoded image of our shipped payload, so the residual
             # is exactly the information the swarm never received.
-            r = lax.axis_index(topo.axis)
-            corrected = jax.tree.map(
-                lambda g, e: g.astype(jnp.float32) + e[r], grads, ef
-            )
-            avg, local_image, new_mailbox = protocol.combine_ef(
-                corrected, ctx, key=step_key, state=mailbox
-            )
-            residual = jax.tree.map(
-                lambda c, l: c - l.astype(jnp.float32), corrected, local_image
-            )
-            # Re-gather the per-peer rows so the replicated carry stays
-            # identical on every mesh slice (same layout as the async ring).
-            new_ef = jax.tree.map(
-                lambda x: lax.all_gather(x, topo.axis), residual
-            )
+            with jax.named_scope("p2p.ef"):
+                r = lax.axis_index(topo.axis)
+                corrected = jax.tree.map(
+                    lambda g, e: g.astype(jnp.float32) + e[r], grads, ef
+                )
+            with exchange_scope():
+                avg, local_image, new_mailbox = protocol.combine_ef(
+                    corrected, ctx, key=step_key, state=mailbox
+                )
+            with jax.named_scope("p2p.ef"):
+                residual = jax.tree.map(
+                    lambda c, l: c - l.astype(jnp.float32), corrected, local_image
+                )
+                # Re-gather the per-peer rows so the replicated carry stays
+                # identical on every mesh slice (same layout as the async ring).
+                new_ef = jax.tree.map(
+                    lambda x: lax.all_gather(x, topo.axis), residual
+                )
         else:
-            avg, new_mailbox = protocol.combine(
-                grads, ctx, key=step_key, state=mailbox
-            )
+            with exchange_scope():
+                avg, new_mailbox = protocol.combine(
+                    grads, ctx, key=step_key, state=mailbox
+                )
             new_ef = None
-        lr = schedule(step_idx)
-        updates, opt_state = optimizer.update(avg, opt_state, params, lr)
-        params = apply_updates(params, updates)
+        with jax.named_scope("p2p.optimizer"):
+            lr = schedule(step_idx)
+            updates, opt_state = optimizer.update(avg, opt_state, params, lr)
+            params = apply_updates(params, updates)
         if topo.peer_axes:
-            loss = lax.pmean(loss, topo.axis)
+            with jax.named_scope("p2p.exchange"):
+                loss = lax.pmean(loss, topo.axis)
         metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr, "aux": aux}
         return params, opt_state, metrics, new_mailbox, new_ef
 
