@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops
+from repro.kernels import ops, qsgd
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.qsgd import qsgd_dequantize, qsgd_quantize
 from repro.kernels.ref import (
@@ -20,9 +20,23 @@ from repro.kernels.ssd_scan import ssd_scan_pallas
 # QSGD
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("nb", [1, 7, 8, 33])
-@pytest.mark.parametrize("bucket", [128, 256, 2048])
-@pytest.mark.parametrize("s", [1, 15, 127])
+# rows per grid step at bucket 512 (800 and 1,344)
+QUANT_TILE = qsgd.tile_rows(10**6, 512, (4, 4, 1), 1)
+DEQUANT_TILE = qsgd.tile_rows(10**6, 512, (1, 4), 1)
+QUANT_CASES = [
+    (nb, bucket, s)
+    for s in (1, 15, 127) for bucket in (128, 256, 2048) for nb in (1, 7, 8, 33)
+] + [  # a ragged last block at the tiles of bucket 512
+    (nb, 512, s)
+    for s in (15, 127)
+    for nb in (QUANT_TILE + 1, 2 * QUANT_TILE - 1, DEQUANT_TILE + 1)
+]
+
+
+@pytest.mark.parametrize(
+    "nb,bucket,s",
+    [pytest.param(nb, b, s, id=f"{s}-{b}-{nb}") for nb, b, s in QUANT_CASES],
+)
 def test_qsgd_quantize_matches_ref(nb, bucket, s):
     key = jax.random.PRNGKey(nb * 1000 + bucket + s)
     x = jax.random.normal(key, (nb, bucket)) * 3.0
@@ -34,6 +48,79 @@ def test_qsgd_quantize_matches_ref(nb, bucket, s):
     dq_k = qsgd_dequantize(lev_k, nrm_k, s, interpret=True)
     dq_r = qsgd_dequantize_ref(lev_r, nrm_r, s)
     np.testing.assert_allclose(np.asarray(dq_k), np.asarray(dq_r), rtol=1e-6)
+
+
+def _kernel_outputs(nb, bucket, P):
+    key = jax.random.PRNGKey(nb + P)
+    x = jax.random.normal(key, (nb, bucket)) * 3.0
+    u = jax.random.uniform(jax.random.fold_in(key, 1), (nb, bucket))
+    lev, nrm = qsgd.qsgd_quantize.__wrapped__(x, u, 127, interpret=True)
+    banks = jax.random.randint(jax.random.fold_in(key, 2), (P, nb, bucket), -127, 128, jnp.int8)
+    bank_nrm = jax.random.uniform(jax.random.fold_in(key, 3), (P, nb), jnp.float32, 0.1, 2.0)
+    w = jax.random.uniform(jax.random.fold_in(key, 4), (P,), jnp.float32)
+    return (
+        lev, nrm,
+        qsgd.qsgd_dequantize.__wrapped__(lev, nrm, 127, interpret=True),
+        qsgd.qsgd_dequant_reduce.__wrapped__(banks, bank_nrm, w, 127, interpret=True),
+    )
+
+
+@pytest.mark.parametrize("nb,P", [(801, 1), (1345, 4)])
+def test_qsgd_kernels_bit_identical_to_32_row_blocks(monkeypatch, nb, P):
+    """The block height enters no arithmetic: every output of the three
+    kernels equals, bit for bit, what 32-row blocks compute."""
+    tiled = _kernel_outputs(nb, 512, P)
+    monkeypatch.setattr(qsgd, "tile_rows", lambda *a: qsgd.ROW_ALIGN)
+    rows32 = _kernel_outputs(nb, 512, P)
+    for got, want in zip(tiled, rows32):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _vgg11_leaf_sizes():
+    from repro.configs import get_config
+    from repro.models.cnn import init_cnn
+
+    shapes = jax.eval_shape(lambda k: init_cnn(k, get_config("vgg11")), jax.random.PRNGKey(0))
+    return [int(np.prod(l.shape)) for l in jax.tree.leaves(shapes)]
+
+
+def _kernel_blocks(P):
+    """Each kernel's (itemsizes, norm columns), as its entry sizes its blocks."""
+    return {
+        "quantize": ((4, 4, 1), 1),
+        "dequantize": ((1, 4), 1),
+        "dequant_reduce": ((1,) * P + (4,), P),
+    }
+
+
+@pytest.mark.parametrize("P", [1, 4])
+def test_qsgd_tile_rows_on_vgg11_leaves(P):
+    sizes = _vgg11_leaf_sizes()
+    assert len(sizes) == 22 and sum(sizes) == 28_144_010
+    nbs = [-(-n // 512) for n in sizes]
+    assert sum(-(-nb // 32) for nb in nbs) == 1730  # grid steps of 32-row blocks
+    for name, (items, cols) in _kernel_blocks(P).items():
+        steps = 0
+        for nb in nbs:
+            rows = qsgd.tile_rows(nb, 512, items, cols)
+            assert rows % 32 == 0 and 32 <= rows <= -(-nb // 32) * 32, (name, nb, rows)
+            steps += -(-nb // rows)
+        assert steps <= 130, (name, steps)
+
+
+@pytest.mark.parametrize("bucket", [128, 512, 2048])
+@pytest.mark.parametrize("P", [1, 4, 16])
+@pytest.mark.parametrize("nb", [1, 33, 8192, 32768])
+def test_qsgd_tile_rows_fit_vmem_budget(bucket, P, nb):
+    cap = -(-nb // 32) * 32
+    for name, (items, cols) in _kernel_blocks(P).items():
+        rows = qsgd.tile_rows(nb, bucket, items, cols)
+        # every block twice (double-buffered); a norm column fills 128 f32 lanes
+        step_bytes = 2 * (bucket * sum(items) + cols * 128 * 4)
+        assert rows * step_bytes <= qsgd.VMEM_BUDGET, (name, rows)
+        assert rows % 32 == 0 and rows <= cap, (name, rows)
+        # the largest such: 32 rows more would overflow the budget or pass nb
+        assert (rows + 32) * step_bytes > qsgd.VMEM_BUDGET or rows == cap, (name, rows)
 
 
 def test_qsgd_zero_bucket():

@@ -32,7 +32,12 @@ SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 @pytest.mark.parametrize("P", [1, 2, 4])
-@pytest.mark.parametrize("nb,bucket", [(1, 128), (5, 256), (8, 128), (13, 512)])
+# the last three leave a ragged last block at bucket 512's tiles of
+# 672 (P = 4), 1024 (P = 2) and 1344 (P = 1) rows
+@pytest.mark.parametrize(
+    "nb,bucket",
+    [(1, 128), (5, 256), (8, 128), (13, 512), (673, 512), (1343, 512), (1345, 512)],
+)
 @pytest.mark.parametrize("s", [3, 127])
 def test_dequant_reduce_matches_unfused_ref(P, nb, bucket, s):
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(nb * 1000 + bucket + s), 3)

@@ -76,19 +76,26 @@ def _assert_kernel(compiled):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("n", [FC2, CONV, 40960 + 7])
-def test_qsgd_kernels_compile(one_chip, n):
-    nb = -(-n // BUCKET)
+@pytest.mark.parametrize("n,bucket", [
+    pytest.param(FC2, BUCKET, id=str(FC2)),
+    pytest.param(CONV, BUCKET, id=str(CONV)),
+    pytest.param(40960 + 7, BUCKET, id=str(40960 + 7)),
+    pytest.param(FC2, 2048, id=f"{FC2}-2048"),  # QSGDConfig's default bucket
+])
+def test_qsgd_kernels_compile(one_chip, n, bucket):
+    """Blocks sized by ``qsgd.tile_rows`` (up to 1,344 rows of 512, ragged
+    last block) lower and fit VMEM."""
+    nb = -(-n // bucket)
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    x = S((nb, BUCKET), jnp.float32)
+    x = S((nb, bucket), jnp.float32)
     _assert_kernel(_compile(lambda b, u: qsgd.qsgd_quantize(b, u, 127, interpret=False), x, x))
     _assert_kernel(_compile(
         lambda l, m: qsgd.qsgd_dequantize(l, m, 3, interpret=False),
-        S((nb, BUCKET), jnp.int8), S((nb,), jnp.float32),
+        S((nb, bucket), jnp.int8), S((nb,), jnp.float32),
     ))
     _assert_kernel(_compile(
         lambda l, m, w: qsgd.qsgd_dequant_reduce(l, m, w, 3, interpret=False),
-        S((4, nb, BUCKET), jnp.int8), S((4, nb), jnp.float32), S((4,), jnp.float32),
+        S((4, nb, bucket), jnp.int8), S((4, nb), jnp.float32), S((4,), jnp.float32),
     ))
 
 
