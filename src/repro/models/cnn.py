@@ -1,9 +1,11 @@
 """The paper's own CNNs — VGG-11, MobileNetV3-Small, SqueezeNet 1.1 — in
 pure JAX (NHWC, ``lax.conv_general_dilated``).
 
-BatchNorm is applied in batch-statistics mode (no running averages): every
-peer normalizes with its own batch moments, which matches what the paper's
-per-peer PyTorch training does during the measured training stages.
+BatchNorm is applied in batch-statistics mode: every peer normalizes with
+its own batch moments, which is what the paper's per-peer PyTorch training
+does in its training stages. No running averages are kept, because they
+never enter a training step's loss or gradient (only evaluation reads them)
+and keeping them would add state that every exchange and checkpoint carries.
 """
 from __future__ import annotations
 
@@ -17,13 +19,19 @@ from jax import lax
 Params = Dict[str, Any]
 
 
-def _conv_init(key, kh, kw, cin, cout, dtype=jnp.float32):
+def _conv_w(key, kh, kw, cin, cout, dtype=jnp.float32):
+    """A conv without bias (He-normal over its fan-in)."""
     fan_in = kh * kw * cin
     w = jax.random.normal(key, (kh, kw, cin, cout)) * math.sqrt(2.0 / fan_in)
-    return {"w": w.astype(dtype), "b": jnp.zeros((cout,), dtype)}
+    return {"w": w.astype(dtype)}
+
+
+def _conv_init(key, kh, kw, cin, cout, dtype=jnp.float32):
+    return {**_conv_w(key, kh, kw, cin, cout, dtype), "b": jnp.zeros((cout,), dtype)}
 
 
 def conv2d(p: Params, x, stride=1, padding="SAME", groups=1):
+    """``p["b"]`` is optional: a conv that feeds a batch norm has none."""
     y = lax.conv_general_dilated(
         x, p["w"],
         window_strides=(stride, stride),
@@ -31,14 +39,14 @@ def conv2d(p: Params, x, stride=1, padding="SAME", groups=1):
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         feature_group_count=groups,
     )
-    return y + p["b"]
+    return y + p["b"] if "b" in p else y
 
 
 def _bn_init(c, dtype=jnp.float32):
     return {"scale": jnp.ones((c,), dtype), "bias": jnp.zeros((c,), dtype)}
 
 
-def batchnorm(p: Params, x, eps=1e-5):
+def batchnorm(p: Params, x, eps=1e-3):  # torchvision's MobileNetV3 eps
     mu = x.mean(axis=(0, 1, 2), keepdims=True)
     var = x.var(axis=(0, 1, 2), keepdims=True)
     return (x - mu) * lax.rsqrt(var + eps) * p["scale"] + p["bias"]
@@ -162,6 +170,15 @@ def squeezenet_forward(params: Params, images: jnp.ndarray, cfg) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # MobileNetV3-Small
 # ---------------------------------------------------------------------------
+# Howard et al. 2019 (arXiv:1905.02244), Table 2, as torchvision's
+# ``mobilenet_v3_small`` builds it: convs that feed a batch norm have no
+# bias, a block has no expand conv where its expansion equals its input,
+# the SE squeeze is ``_make_divisible(exp // 4)`` wide with a hard-sigmoid
+# gate, and every conv pads (k - 1) // 2 on each side. Below 64 px the stem
+# takes stride 1, so 32 px maps run 32 -> 16 -> 8 -> 4 -> 2. The
+# classifier's dropout is left out: the step is deterministic. Each block's
+# depthwise conv, its batch norm and activation sit in one ``cnn.depthwise``
+# scope, which a device trace reads back through the HLO's op names.
 
 # (kernel, exp, out, SE, activation, stride)
 _MBV3_PLAN = [
@@ -179,35 +196,51 @@ _MBV3_PLAN = [
 ]
 
 
+def _make_divisible(v, divisor=8):
+    """The nearest multiple of ``divisor`` to v, at least divisor and never
+    more than 10% below v (torchvision's rounding of the SE widths)."""
+    new = max(divisor, int(v + divisor / 2) // divisor * divisor)
+    return new + divisor if new < 0.9 * v else new
+
+
+def _hsigmoid(x):
+    return jax.nn.relu6(x + 3) / 6
+
+
 def _act(x, kind):
-    return jax.nn.relu(x) if kind == "relu" else x * jax.nn.relu6(x + 3) / 6
+    return jax.nn.relu(x) if kind == "relu" else x * _hsigmoid(x)
+
+
+def _same(k):
+    p = (k - 1) // 2
+    return ((p, p), (p, p))
 
 
 def init_mobilenet_v3_small(key, cfg) -> Params:
     ks = iter(jax.random.split(key, 8 + 8 * len(_MBV3_PLAN)))
     p: Params = {
-        "stem": _conv_init(next(ks), 3, 3, cfg.image_channels, 16),
+        "stem": _conv_w(next(ks), 3, 3, cfg.image_channels, 16),
         "stem_bn": _bn_init(16),
     }
     cin = 16
     blocks = []
     for (k, exp, out, se, actk, stride) in _MBV3_PLAN:
-        b: Params = {
-            "expand": _conv_init(next(ks), 1, 1, cin, exp),
-            "expand_bn": _bn_init(exp),
-            "dw": _conv_init(next(ks), k, k, 1, exp),
-            "dw_bn": _bn_init(exp),
-            "project": _conv_init(next(ks), 1, 1, exp, out),
-            "project_bn": _bn_init(out),
-        }
+        b: Params = {}
+        if exp != cin:
+            b["expand"] = _conv_w(next(ks), 1, 1, cin, exp)
+            b["expand_bn"] = _bn_init(exp)
+        b["dw"] = _conv_w(next(ks), k, k, 1, exp)
+        b["dw_bn"] = _bn_init(exp)
         if se:
-            sq = max(exp // 4, 8)
+            sq = _make_divisible(exp // 4)
             b["se_fc1"] = _conv_init(next(ks), 1, 1, exp, sq)
             b["se_fc2"] = _conv_init(next(ks), 1, 1, sq, exp)
+        b["project"] = _conv_w(next(ks), 1, 1, exp, out)
+        b["project_bn"] = _bn_init(out)
         blocks.append(b)
         cin = out
     p["blocks"] = blocks
-    p["head_conv"] = _conv_init(next(ks), 1, 1, cin, 576)
+    p["head_conv"] = _conv_w(next(ks), 1, 1, cin, 576)
     p["head_bn"] = _bn_init(576)
     p["fc1"] = _linear_init(next(ks), 576, 1024)
     p["fc2"] = _linear_init(next(ks), 1024, cfg.num_classes)
@@ -215,24 +248,26 @@ def init_mobilenet_v3_small(key, cfg) -> Params:
 
 
 def mobilenet_v3_small_forward(params: Params, images: jnp.ndarray, cfg) -> jnp.ndarray:
-    small = cfg.image_size < 64
-    x = conv2d(params["stem"], images, stride=1 if small else 2)
-    x = _act(batchnorm(params["stem_bn"], x), "hswish")
+    def conv_bn(p, bn, x, **kw):
+        return batchnorm(bn, conv2d(p, x, **kw))
+
+    x = conv_bn(params["stem"], params["stem_bn"], images,
+                stride=1 if cfg.image_size < 64 else 2, padding=_same(3))
+    x = _act(x, "hswish")
     for b, (k, exp, out, se, actk, stride) in zip(params["blocks"], _MBV3_PLAN):
-        if small and x.shape[1] <= 4:
-            stride = 1  # don't collapse tiny feature maps below 4x4
-        inp = x
-        h = _act(batchnorm(b["expand_bn"], conv2d(b["expand"], x)), actk)
-        h = conv2d(b["dw"], h, stride=stride, groups=h.shape[-1])
-        h = _act(batchnorm(b["dw_bn"], h), actk)
-        if "se_fc1" in b:
+        h = x
+        if "expand" in b:
+            h = _act(conv_bn(b["expand"], b["expand_bn"], h), actk)
+        with jax.named_scope("cnn.depthwise"):
+            h = conv_bn(b["dw"], b["dw_bn"], h, stride=stride, padding=_same(k), groups=exp)
+            h = _act(h, actk)
+        if se:
             s = h.mean(axis=(1, 2), keepdims=True)
             s = jax.nn.relu(conv2d(b["se_fc1"], s))
-            s = jax.nn.sigmoid(conv2d(b["se_fc2"], s))
-            h = h * s
-        h = batchnorm(b["project_bn"], conv2d(b["project"], h))
-        x = h + inp if (stride == 1 and inp.shape[-1] == h.shape[-1]) else h
-    x = _act(batchnorm(params["head_bn"], conv2d(params["head_conv"], x)), "hswish")
+            h = h * _hsigmoid(conv2d(b["se_fc2"], s))
+        h = conv_bn(b["project"], b["project_bn"], h)
+        x = h + x if stride == 1 and x.shape[-1] == out else h
+    x = _act(conv_bn(params["head_conv"], params["head_bn"], x), "hswish")
     x = x.mean(axis=(1, 2))
     x = _act(linear(params["fc1"], x), "hswish")
     return linear(params["fc2"], x)
