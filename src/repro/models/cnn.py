@@ -10,6 +10,7 @@ and keeping them would add state that every exchange and checkpoint carries.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Dict, List, Tuple
 
 import jax
@@ -53,9 +54,58 @@ def batchnorm(p: Params, x, eps=1e-3):  # torchvision's MobileNetV3 eps
 
 
 def max_pool(x, window=2, stride=2):
+    """VALID max pool of NHWC maps. Windows that tile the map exactly
+    (``window == stride``, both sizes divisible by it) take
+    ``_tiled_max_pool``, whose backward is elementwise; any other shape
+    keeps ``reduce_window`` and its ``select_and_scatter`` backward."""
+    h, w = x.shape[1:3]
+    if window == stride and h % window == 0 and w % window == 0:
+        return _tiled_max_pool(x, window)
+    return _reduce_window_max(x, window, stride)
+
+
+def _reduce_window_max(x, window, stride):
     return lax.reduce_window(
         x, -jnp.inf, lax.max, (1, window, window, 1), (1, stride, stride, 1), "VALID"
     )
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _tiled_max_pool(x, k):
+    return _reduce_window_max(x, k, k)
+
+
+def _tiled_max_pool_fwd(x, k):
+    """The pooled maps and, as the only residual, each window's index
+    (row-major, 0..k*k-1) of its first maximum: the element that
+    ``select_and_scatter`` with JAX's ``ge`` select routes the gradient to."""
+    y = _reduce_window_max(x, k, k)
+    dtype = jnp.int8 if k * k <= 128 else jnp.int32
+    first = jnp.full(y.shape, k * k - 1, dtype)
+    for p in reversed(range(k * k - 1)):
+        i, j = divmod(p, k)
+        view = lax.slice(x, (0, i, j, 0), x.shape, (1, k, k, 1))
+        first = jnp.where(view == y, jnp.asarray(p, dtype), first)
+    return y, first
+
+
+def _tiled_max_pool_bwd(k, first, g):
+    """g_x[n, h, w, c] = g[n, h // k, w // k, c] where ``first`` there is
+    (h % k) * k + w % k, else 0. H and W are untiled dims of the chip's
+    NHWC layout, so the upsampling reshape is a bitcast."""
+    n, ho, wo, c = g.shape
+
+    def up(a):
+        a = lax.broadcast_in_dim(a, (n, ho, k, wo, k, c), (0, 1, 3, 5))
+        return a.reshape(n, ho * k, wo * k, c)
+
+    hw = (1, ho * k, wo * k, 1)
+    at = (lax.broadcasted_iota(jnp.int32, hw, 1) % k * k
+          + lax.broadcasted_iota(jnp.int32, hw, 2) % k).astype(first.dtype)
+    return (jnp.where(up(first) == at, up(g), jnp.zeros((), g.dtype)),)
+
+
+_tiled_max_pool.defvjp(_tiled_max_pool_fwd, _tiled_max_pool_bwd)
 
 
 def avg_pool_to(x, out_hw: int):

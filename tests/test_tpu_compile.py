@@ -187,3 +187,11 @@ def test_vgg11_p2p_step_compiles_with_kernels(step_programs, peers, lambdas, exc
     mem = compiled.memory_analysis()
     # params + EF bank + batch, per device, fit a 16 GB v5e with room to spare
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4 * 2**30
+
+
+@pytest.mark.parametrize("peers,lambdas,exchange", STEPS)
+def test_vgg11_p2p_step_pools_without_select_and_scatter(step_programs, peers, lambdas,
+                                                          exchange):
+    """vgg11's 2x2 pools tile their maps, so their backward is an
+    elementwise select and the step holds no ``select-and-scatter``."""
+    assert "select-and-scatter" not in step_programs[(peers, lambdas, exchange)].as_text()
